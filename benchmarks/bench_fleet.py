@@ -6,10 +6,12 @@ Four questions:
 * does sharding ``simulate_batch`` across devices pay on a wide candidate
   sweep (the fleet scheduler's joint-scoring shape)?  A 128-candidate
   sweep is timed on the single-device vmap path and the pmap-sharded path.
-  Sharding needs >1 device, so when the current process sees a single
-  device the measurement re-execs itself in a subprocess with
+  Sharding needs >1 device.  On a CPU host that sees one device the
+  measurement re-execs itself in a CPU-only subprocess with
   ``--xla_force_host_platform_device_count=8`` (the multi-device-smoke CI
-  pattern);
+  pattern) and labels its rows as CPU; on a one-chip accelerator host the
+  sharded row is ``not measured`` (a child process cannot share the chip
+  this process holds);
 * what does one joint 3-tenant scheduling round cost end to end
   (budget-constrained allocation + bin-packing + one batched scoring
   call)?
@@ -63,8 +65,11 @@ def _sweep_times() -> dict:
         )
 
     _, us_single = timed(run, 1, repeats=3, warmup=1)
-    _, us_sharded = timed(run, None, repeats=3, warmup=1)
+    us_sharded = None
+    if jax.local_device_count() > 1:
+        _, us_sharded = timed(run, None, repeats=3, warmup=1)
     return {
+        "platform": jax.default_backend(),
         "devices": jax.local_device_count(),
         "us_single": us_single,
         "us_sharded": us_sharded,
@@ -72,12 +77,14 @@ def _sweep_times() -> dict:
 
 
 def _sweep_times_forced_multidevice() -> dict:
-    """Re-exec the sweep with 8 fake host devices (subprocess: XLA device
-    count is fixed at backend init, so it cannot change in-process)."""
+    """Re-exec the sweep on the CPU with 8 fake host devices (subprocess:
+    XLA device count is fixed at backend init, so it cannot change
+    in-process).  Only for a CPU parent: the child is pinned to the CPU."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
     ).strip()
+    env["JAX_PLATFORMS"] = "cpu"
     env[_SWEEP_ENV] = "1"
     env.setdefault("PYTHONPATH", "src")
     out = subprocess.run(
@@ -92,21 +99,26 @@ def _sweep_times_forced_multidevice() -> dict:
 def run() -> dict:
     import jax
 
-    if jax.local_device_count() > 1:
+    if jax.local_device_count() > 1 or jax.default_backend() != "cpu":
         sweep = _sweep_times()
     else:
         sweep = _sweep_times_forced_multidevice()
-    speedup = sweep["us_single"] / max(sweep["us_sharded"], 1e-9)
+    where = f"platform={sweep['platform']}"
     emit(
         f"simulate_batch_{N_CANDIDATES}cand_single_device",
         sweep["us_single"],
-        f"devices=1;candidates={N_CANDIDATES}",
+        f"{where};devices=1;candidates={N_CANDIDATES}",
     )
-    emit(
-        f"simulate_batch_{N_CANDIDATES}cand_sharded",
-        sweep["us_sharded"],
-        f"devices={sweep['devices']};speedup={speedup:.2f}x_vs_vmap",
-    )
+    if sweep["us_sharded"] is None:
+        print(f"simulate_batch_{N_CANDIDATES}cand_sharded,not measured,"
+              f"{where};devices=1")
+    else:
+        speedup = sweep["us_single"] / max(sweep["us_sharded"], 1e-9)
+        emit(
+            f"simulate_batch_{N_CANDIDATES}cand_sharded",
+            sweep["us_sharded"],
+            f"{where};devices={sweep['devices']};speedup={speedup:.2f}x_vs_vmap",
+        )
 
     # one joint 3-tenant scheduling round, end to end
     from repro.control import GuardBands

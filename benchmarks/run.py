@@ -10,7 +10,7 @@ from __future__ import annotations
 import sys
 import time
 
-from .common import dump_json
+from .common import dump_json, enable_compile_cache
 
 BENCHES = [
     ("table2", "bench_table2", "Paper Table 2 — WordCount sensitivity + prediction"),
@@ -33,6 +33,7 @@ BENCHES = [
 
 def main() -> None:
     selected = set(sys.argv[1:])
+    enable_compile_cache()
     print("name,us_per_call,derived")
     t0 = time.perf_counter()
     for key, module, desc in BENCHES:

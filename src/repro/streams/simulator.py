@@ -65,6 +65,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..core.dag import Configuration, Grouping
 from ..core.metrics import STREAM_MANAGER, InstanceSamples, MetricsStore
@@ -375,7 +376,8 @@ def degree_bucket_size(n: int, floor: int = 0) -> int:
 #: gather/scatter overhead per edge; the decision uses *unpadded* counts,
 #: so it is invariant to bucket floors and batch padding (bitwise-stable
 #: bucketing semantics).  Shuffle-heavy DAGs (wordcount's p×p exchange,
-#: density ≈ 1/4) stay dense; pipelines (deep_pipeline ≈ 0.11) go sparse.
+#: density ≈ 1/4) stay dense; pipelines (deep_pipeline ≈ 0.11) go sparse
+#: — on the CPU; a TPU always runs dense (see :func:`resolve_tick_kernel`).
 SPARSE_DENSITY_THRESHOLD = 0.125
 
 TICK_KERNELS = ("dense", "sparse", "auto")
@@ -393,7 +395,12 @@ def resolve_tick_kernel(n_inst: int, n_edges: int, tick_kernel: str = "auto") ->
 
     ``n_inst`` / ``n_edges`` are the *unpadded* maxima across the batch;
     ``"auto"`` picks ``"sparse"`` when ``n_edges ≤ threshold · n_inst²``
-    and ``"dense"`` otherwise (the dense path stays the oracle).
+    and ``"dense"`` otherwise (the dense path stays the oracle).  On a TPU
+    ``"auto"`` is always ``"dense"``: the sparse path's per-edge gathers run
+    far slower there than the dense path's (I, I) vector work (on a TPU v5e
+    a 512-candidate deep_pipeline sweep at the 32,768-edge bucket did not
+    finish in 13 minutes; a 512-candidate wordcount sweep at the same
+    512-instance bucket, dense, took 15 s with its compile).
     """
     if tick_kernel not in TICK_KERNELS:
         raise ValueError(
@@ -401,6 +408,8 @@ def resolve_tick_kernel(n_inst: int, n_edges: int, tick_kernel: str = "auto") ->
         )
     if tick_kernel != "auto":
         return tick_kernel
+    if _platform() == "tpu":
+        return "dense"
     dense_cells = max(int(n_inst), 1) ** 2
     return "sparse" if n_edges <= SPARSE_DENSITY_THRESHOLD * dense_cells else "dense"
 
@@ -539,6 +548,25 @@ def _one_hot(cont_of: jnp.ndarray, n_cont: int) -> jnp.ndarray:
     return (cont_of[:, None] == jnp.arange(n_cont)[None, :]).astype(jnp.float32)
 
 
+#: Precision of the one-hot instance→container contractions.  A TPU matmul
+#: unit pass at default precision rounds f32 operands to bf16 (≈0.4% per
+#: tick, which the multiplicative backpressure loop would compound over
+#: hundreds of ticks); HIGHEST keeps them f32-exact whichever lowering the
+#: compiler picks for a shape.  CPU dots are f32 either way.
+_EXACT = jax.lax.Precision.HIGHEST
+
+
+def _platform() -> str:
+    """The backend new arrays land on: the ``jax.default_device`` override
+    when one is set, else the default backend.  Every cache that holds
+    device results or buffers keys on it, so an entry made on one backend
+    never answers another (a CPU reference run beside a TPU run)."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
 def _summarize_windowed(samples: dict, is_source) -> dict:
     """THE summary reductions — the single definition both modes share.
 
@@ -643,7 +671,10 @@ def _simulate_core(
     mem_slope = arrays["mem_slope"]
     inst_mask = arrays["inst_mask"]
     cont_mask = arrays["cont_mask"]
-    C = _one_hot(arrays["cont_of"], cont_cpus.shape[0])  # (I, K)
+    cont_of = arrays["cont_of"]
+    # (I, K) one-hot: container sums are exact contractions against it;
+    # container → instance broadcasts are the exact gather ``x[cont_of]``
+    C = _one_hot(cont_of, cont_cpus.shape[0])
     n_inst = busy_cost.shape[0]
     n_cont = cont_cpus.shape[0]
     n_src = jnp.maximum(is_source.sum(), 1)
@@ -698,9 +729,9 @@ def _simulate_core(
         want = want * inst_mask
 
         # 3) container CPU contention (incl. last tick's SM CPU)
-        demand = C.T @ (want * cpu_cost) + sm_cpu_prev  # (K,) CPU-seconds
+        demand = jnp.matmul(C.T, want * cpu_cost, precision=_EXACT) + sm_cpu_prev
         scale_c = jnp.minimum(1.0, cont_cpus * dt / jnp.maximum(demand, 1e-9))
-        proc = want * (C @ scale_c)
+        proc = want * scale_c[cont_of]
         qin = qin - jnp.where(is_source, 0.0, proc)
         out_copies = proc * gamma * rowsum
         qout = qout + out_copies
@@ -711,27 +742,30 @@ def _simulate_core(
             # desired flow matrix if everything in qout were released this tick
             share = W / jnp.maximum(rowsum, 1e-9)[:, None]
             F_want = qout[:, None] * share                  # (I, I) copies
-            orig_c = C.T @ F_want.sum(axis=1)               # per-source-SM traversals
-            arr_c = ((F_want * remote).sum(axis=0)) @ C     # per-dest-SM net arrivals
+            # per-source-SM traversals and per-dest-SM net arrivals
+            orig_c = jnp.matmul(C.T, F_want.sum(axis=1), precision=_EXACT)
+            arr_c = jnp.matmul((F_want * remote).sum(axis=0), C, precision=_EXACT)
             s_c = jnp.minimum(1.0, sm_budget / jnp.maximum(orig_c + arr_c, 1e-9))
-            s_src = C @ s_c
-            s_dst = C @ s_c
+            s_inst = s_c[cont_of]
             # a flow is limited by the slowest SM on its path (source SM
             # always; destination SM only when crossing containers)
             eff = jnp.minimum(
-                s_src[:, None], jnp.where(remote, s_dst[None, :], 1.0)
+                s_inst[:, None], jnp.where(remote, s_inst[None, :], 1.0)
             )
             F = F_want * eff
             delivered_from = F.sum(axis=1)
             arrivals = F.sum(axis=0)
-            trav_c = C.T @ F.sum(axis=1) + (F * remote).sum(axis=0) @ C
+            trav_c = (
+                jnp.matmul(C.T, F.sum(axis=1), precision=_EXACT)
+                + jnp.matmul((F * remote).sum(axis=0), C, precision=_EXACT)
+            )
         else:
             # same physics in edge-list form: gather → throttle → gather,
             # with per-instance CSR sums aggregated to containers by the
             # (I, K) one-hot matmul (identical grouping, O(E + I·K) per tick)
             f_want = qout[e_src] * e_share
-            orig_c = _by_src(f_want) @ C
-            arr_c = _by_dst(f_want * e_remote) @ C
+            orig_c = jnp.matmul(_by_src(f_want), C, precision=_EXACT)
+            arr_c = jnp.matmul(_by_dst(f_want * e_remote), C, precision=_EXACT)
             s_c = jnp.minimum(1.0, sm_budget / jnp.maximum(orig_c + arr_c, 1e-9))
             eff = jnp.minimum(
                 s_c[e_sc], jnp.where(e_remote > 0, s_c[e_dc], 1.0)
@@ -739,7 +773,10 @@ def _simulate_core(
             f = f_want * eff
             delivered_from = _by_src(f)
             arrivals = _by_dst(f)
-            trav_c = delivered_from @ C + _by_dst(f * e_remote) @ C
+            trav_c = (
+                jnp.matmul(delivered_from, C, precision=_EXACT)
+                + jnp.matmul(_by_dst(f * e_remote), C, precision=_EXACT)
+            )
         qout = qout - delivered_from
         qin = qin + jnp.where(is_source, 0.0, arrivals)
 
@@ -856,18 +893,20 @@ def _get_batch_kernel(batch: int, n_inst: int, n_cont: int, n_ticks: int,
     """``batch`` is the per-device batch when ``n_devices > 1``."""
     # Donate the padded batch buffers (stacked structure arrays,
     # per-tick loads, seeds): they are rebuilt from host numpy on every
-    # call, so XLA may reuse their memory for outputs — on
-    # 100+-candidate sweeps that halves peak device memory.  CPU XLA
-    # cannot donate (it would only warn), so donation is enabled on
-    # accelerators only.  Resident batches (the staging cache) must
-    # survive the call, so they exclude the structure arrays (arg 0).
-    # The cache key carries the *effective* donate tuple, so on CPU a
-    # resident and a non-resident call at the same shapes share one compile.
+    # call, so XLA may reuse their memory for full-mode trajectories.
+    # Summary outputs are O(B·I) and alias none of them, and CPU XLA
+    # cannot donate at all (either would only warn at every compile), so
+    # donation is enabled for full mode on accelerators only.  Resident
+    # batches (the staging cache) must survive the call, so they exclude
+    # the structure arrays (arg 0).  The cache key carries the *effective*
+    # donate tuple, so where nothing is donated a resident and a
+    # non-resident call at the same shapes share one compile.
+    platform = _platform()
     donate = (0, 1, 2) if donate_batch else (1, 2)
-    if jax.default_backend() == "cpu":
+    if platform == "cpu" or samples_mode == "summary":
         donate = ()
     key = (batch, n_inst, n_cont, n_ticks, sample_every, n_devices,
-           backend, n_edges, d_out, d_in, samples_mode, donate)
+           backend, n_edges, d_out, d_in, samples_mode, donate, platform)
     fn = _KERNEL_CACHE.get(key)
     if fn is None:
         _CACHE_STATS["misses"] += 1
@@ -892,10 +931,10 @@ def _get_batch_kernel(batch: int, n_inst: int, n_cont: int, n_ticks: int,
 
 def kernel_cache_info() -> dict:
     """Tick-kernel compile-cache statistics.  ``misses`` counts distinct
-    ``(batch, bucket_shape, n_ticks, backend)`` traces — i.e. XLA
+    ``(batch, bucket_shape, n_ticks, backend, platform)`` traces — i.e. XLA
     compilations.  ``entries`` describes each resident compiled kernel
     (per-device batch, bucket shape, edge bucket, tick count, device count,
-    backend), so BENCH extras record exactly what compiled.
+    backend, platform), so BENCH extras record exactly what compiled.
     """
     return {
         "size": len(_KERNEL_CACHE),
@@ -913,6 +952,7 @@ def kernel_cache_info() -> dict:
                 "d_out": k[8],
                 "d_in": k[9],
                 "samples": k[10],
+                "platform": k[12],
             }
             for k in _KERNEL_CACHE
         ],
@@ -930,7 +970,7 @@ def clear_kernel_cache() -> None:
 # ---------------------------------------------------------------------------
 
 #: Stacked + device-resident batch arrays keyed by (configs, params, bucket
-#: shapes, backend, shard layout).  A fleet replan that re-scores the same
+#: shapes, backend, shard layout, platform).  A fleet replan that re-scores the same
 #: pruned candidate ladder reuses the resident buffers instead of paying
 #: np.stack + host→device staging every round.  Value-keyed (Configuration
 #: is hashable-by-value), so identical candidate sets hit regardless of
@@ -976,10 +1016,13 @@ def clear_resident_cache() -> None:
 #: ``bytes_summary`` count device→host bytes moved by :func:`_run_batch`'s
 #: single per-batch ``jax.device_get`` (split by payload mode);
 #: ``refetches`` counts summary-backed results that lazily re-ran full-mode
-#: for trajectory access (learning paths).  BENCH extras and
+#: for trajectory access (learning paths); ``staged_devices`` is the most
+#: distinct devices one batch's staged structure arrays occupied (the shard
+#: count a sharded sweep really reached).  BENCH extras and
 #: :func:`repro.streams.cache.cache_stats` embed this snapshot.
 _TRANSFER_STATS = {
     "batches": 0, "bytes_full": 0, "bytes_summary": 0, "refetches": 0,
+    "staged_devices": 0,
 }
 
 
@@ -1362,10 +1405,11 @@ def simulate_batch(
     accepts a :class:`repro.streams.cache.ResultCache` (anything with
     ``get(key)`` / ``put(key, value, nbytes)``): unique rows are looked up
     and filled by full value key — (config, load, seed, params, tick
-    count, resolved backend, ``cache_token``) — so an identical
+    count, resolved backend, ``cache_token``, platform) — so an identical
     resubmission across calls costs zero kernel executions.  The key
-    carries the *resolved* backend (dense and sparse agree only to float
-    tolerance) but neither buckets nor device/residency layout: results
+    carries the *resolved* backend and the platform (dense and sparse, and
+    CPU and accelerator, agree only to float tolerance) but neither
+    buckets nor device/residency layout: results
     are bitwise invariant to those (the bucketing contract), so an entry
     computed at any layout answers every layout.  ``cache_token`` is the
     caller's invalidation handle — the engine layer passes the learner's
@@ -1455,9 +1499,13 @@ def simulate_batch(
             tick_kernel,
         )
         # the key carries the payload mode: a summary entry must never
-        # answer a full-mode lookup (nor vice versa) — the payloads differ
+        # answer a full-mode lookup (nor vice versa) — the payloads differ;
+        # and the platform: backends agree only to a tolerance, so a CPU
+        # reference must never be answered from accelerator results
+        platform = _platform()
         full_keys = [
-            row_keys[i] + (params, n_ticks, backend, samples, cache_token)
+            row_keys[i] + (params, n_ticks, backend, samples, cache_token,
+                           platform)
             for i in uniq
         ]
         miss = []
@@ -1591,7 +1639,7 @@ def _run_batch(
     if resident:
         stage_key = (
             tuple(configs), params, n_inst_b, n_cont_b, n_edge_b, d_out_b,
-            d_in_b, backend, n_dev, fill,
+            d_in_b, backend, n_dev, fill, _platform(),
         )
         hit = _RESIDENT_CACHE.get(stage_key)
         if hit is not None:
@@ -1611,15 +1659,18 @@ def _run_batch(
         if n_dev > 1:
             # place each shard on its pmap device up front — a resident hit
             # then re-enters pmap with zero host→device transfers
-            devs = jax.local_devices()[:n_dev]
-            stacked_dev = {
-                k: jax.device_put_sharded(list(v), devs)
-                for k, v in stacked.items()
-            }
+            mesh = Mesh(np.array(jax.local_devices()[:n_dev]), ("shard",))
+            stacked_dev = jax.device_put(
+                stacked, NamedSharding(mesh, PartitionSpec("shard"))
+            )
         else:
             stacked_dev = {k: jnp.asarray(v) for k, v in stacked.items()}
         if stage_key is not None:
             _resident_put(stage_key, stacked_dev)
+    _TRANSFER_STATS["staged_devices"] = max(
+        _TRANSFER_STATS["staged_devices"],
+        len(stacked_dev["busy_cost"].devices()),
+    )
 
     per_tick_in = np.asarray(per_tick, np.float32)
     seeds_in = np.asarray(seeds, np.int32)

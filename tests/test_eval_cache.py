@@ -275,6 +275,26 @@ def test_changed_params_seed_misses():
     assert rc.info()["hits"] == 0 and rc.info()["misses"] == 2
 
 
+def test_caches_key_on_platform(monkeypatch):
+    """Result, resident-staging and kernel caches never let an entry made on
+    one backend answer a request made on another."""
+    from repro.streams import simulator as sim
+
+    cfg = _wc_cfg()
+    rc = ResultCache()
+    kw = dict(duration_s=1.0, params=PARAMS, seeds=[7], cache=rc,
+              resident=True, samples="summary")
+    sim.clear_resident_cache()
+    first = simulate_batch([cfg], [300.0], **kw)
+    monkeypatch.setattr(sim, "_platform", lambda: "elsewhere")
+    other = simulate_batch([cfg], [300.0], **kw)
+    assert other[0] is not first[0]
+    assert rc.info()["hits"] == 0 and rc.info()["misses"] == 2
+    assert sim.resident_cache_info()["misses"] == 2
+    assert "elsewhere" in {e["platform"] for e in sim.kernel_cache_info()["entries"]}
+    assert other[0].achieved_ktps == first[0].achieved_ktps
+
+
 def test_model_version_bump_invalidates_evaluator_cache():
     dag = wordcount()
     store = ModelStore(oracle_models(dag, PARAMS.sm_cost_per_ktuple))

@@ -509,11 +509,14 @@ def test_sharded_joint_scoring_matches_unsharded_forced_8_devices():
         import json, sys
         sys.path.insert(0, %r)
         import jax
+        from repro.streams import clear_transfer_stats, transfer_info
         from test_fleet_scale import _fleet_plan_fingerprint
         single = _fleet_plan_fingerprint(1)
+        clear_transfer_stats()
         sharded = _fleet_plan_fingerprint(None)
         print(json.dumps({
             "devices": jax.local_device_count(),
+            "staged_devices": transfer_info()["staged_devices"],
             "identical": single == sharded,
         }))
     """ % os.path.join(REPO, "tests"))
@@ -524,4 +527,5 @@ def test_sharded_joint_scoring_matches_unsharded_forced_8_devices():
     assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["devices"] == 8
+    assert res["staged_devices"] > 1          # the shards really spread out
     assert res["identical"]

@@ -228,6 +228,18 @@ def test_auto_selection_by_workload_density():
     assert resolve_tick_kernel(st_wc.n_inst, st_wc.n_edges, "auto") == "dense"
 
 
+def test_auto_selection_is_dense_on_tpu(monkeypatch):
+    """A TPU runs the dense tick for every density; an explicit choice
+    still passes through."""
+    from repro.streams import simulator as sim
+
+    monkeypatch.setattr(sim, "_platform", lambda: "tpu")
+    assert resolve_tick_kernel(480, 25_200, "auto") == "dense"
+    assert resolve_tick_kernel(480, 25_200, "sparse") == "sparse"
+    monkeypatch.setattr(sim, "_platform", lambda: "cpu")
+    assert resolve_tick_kernel(480, 25_200, "auto") == "sparse"
+
+
 def test_sticky_sparse_evaluator_compiles_at_most_twice():
     """The evaluator pins the auto-resolved backend and edge bucket, so a
     growing candidate stream costs at most two sparse compiles."""
@@ -368,3 +380,18 @@ def test_kernel_cache_info_describes_entries():
     e = entries[0]
     assert e["backend"] == "dense" and e["batch"] == 1
     assert e["n_inst"] >= 2 and e["devices"] >= 1 and e["n_ticks"] > 0
+    assert e["platform"] == "cpu"
+
+
+@pytest.mark.parametrize("samples, donated", [("full", (0, 1, 2)), ("summary", ())])
+def test_accelerator_donates_only_buffers_outputs_can_reuse(monkeypatch, samples, donated):
+    """Full trajectories may reuse the staged batch buffers; O(B·I)
+    summaries alias none of them, so summary kernels donate nothing."""
+    from repro.streams import simulator as sim
+
+    monkeypatch.setattr(sim, "_KERNEL_CACHE", {})
+    monkeypatch.setattr(sim, "_CACHE_STATS", {"hits": 0, "misses": 0})
+    monkeypatch.setattr(sim, "_platform", lambda: "tpu")
+    sim._get_batch_kernel(8, 8, 8, 50, 25, samples_mode=samples)
+    (key,) = sim._KERNEL_CACHE
+    assert key[11] == donated
