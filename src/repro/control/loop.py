@@ -49,6 +49,8 @@ from typing import TYPE_CHECKING, Callable, Iterable, Protocol, runtime_checkabl
 import numpy as np
 
 from ..core.dag import Configuration
+from ..streams import spans
+from ..streams.spans import span
 
 if TYPE_CHECKING:
     from ..streams.engine import ConfigEvaluator
@@ -318,71 +320,73 @@ class ControlLoop:
     # -- load-following interface -------------------------------------------
     def step(self, load: float) -> ControlEvent:
         """One sense→forecast→plan→act→learn iteration for one load sample."""
-        load = float(load)
-        target = self.guards.target_for(load)                       # sense
-        horizon = horizon_targets = None
-        plan_target = target
-        if self.forecaster is not None:                             # forecast
-            # learn phase for the forecaster: score the previous step's
-            # one-step-ahead prediction against the load that arrived
-            # (ForecastTracker defines __len__, so test identity, not truth)
-            if self._last_forecast is not None and self.forecast_tracker is not None:
-                self.forecast_tracker.observe(
-                    float(self._last_forecast[0]), load
-                )
-            self.forecaster.observe(load)
-            raw = np.asarray(self.forecaster.forecast(self.horizon), float)
-            self._last_forecast = raw
-            correction = (
-                self.forecast_tracker.factor()
-                if self.forecast_tracker is not None
-                else 1.0
+        with span(spans.CONTROL_STEP):
+            with span(spans.CONTROL_FORECAST):
+                load = float(load)
+                target = self.guards.target_for(load)               # sense
+                horizon = horizon_targets = None
+                plan_target = target
+                if self.forecaster is not None:                     # forecast
+                    # learn phase for the forecaster: score the previous step's
+                    # one-step-ahead prediction against the load that arrived
+                    # (ForecastTracker defines __len__, so test identity, not truth)
+                    if self._last_forecast is not None and self.forecast_tracker is not None:
+                        self.forecast_tracker.observe(
+                            float(self._last_forecast[0]), load
+                        )
+                    self.forecaster.observe(load)
+                    raw = np.asarray(self.forecaster.forecast(self.horizon), float)
+                    self._last_forecast = raw
+                    correction = (
+                        self.forecast_tracker.factor()
+                        if self.forecast_tracker is not None
+                        else 1.0
+                    )
+                    horizon = raw * correction
+                    horizon_targets = np.array(
+                        [self.guards.target_for(x) for x in horizon]
+                    )
+                    if horizon_targets.size:
+                        plan_target = max(target, float(horizon_targets.max()))
+                # _breached was set when the deployment was last measured — it could
+                # not keep up with the load offered to it.  Capacity-model
+                # deployments (no measurement channel, config is None) have no such
+                # signal; there the model itself is the sensor, and a predicted
+                # shortfall against the *new* target is actionable immediately.
+                breached = self._breached
+                predicted_shortfall = False
+                if not breached and self.action is not None and self.action.config is None:
+                    breached = predicted_shortfall = (
+                        self.action.predicted_capacity < plan_target
+                    )
+                # the guards judge the window peak: capacity is acquired ahead of a
+                # forecast rise, and released only when the whole window allows it
+                act, guard = self.guards.decide(plan_target, self._last_target, breached)
+                cause = ""
+                if act:
+                    if guard == "breach":
+                        cause = "predicted-shortfall" if predicted_shortfall else "measured-sla"
+                    elif self.forecaster is not None:
+                        # proactive iff the instantaneous target alone would NOT
+                        # have produced this same decision — it would have held, or
+                        # acted in the other direction (e.g. sensed says release,
+                        # the window peak says acquire)
+                        act_now, guard_now = self.guards.decide(
+                            target, self._last_target, False
+                        )
+                        if act_now and guard_now == guard:
+                            cause = "guard"
+                        else:
+                            guard = cause = "forecast"
+                    else:
+                        cause = "guard"
+                if self.action is None:
+                    act, guard, cause = True, "bootstrap", "bootstrap"
+            return self._execute(
+                load, target, act, guard,
+                cause=cause, plan_target=plan_target,
+                horizon=horizon, horizon_targets=horizon_targets,
             )
-            horizon = raw * correction
-            horizon_targets = np.array(
-                [self.guards.target_for(x) for x in horizon]
-            )
-            if horizon_targets.size:
-                plan_target = max(target, float(horizon_targets.max()))
-        # _breached was set when the deployment was last measured — it could
-        # not keep up with the load offered to it.  Capacity-model
-        # deployments (no measurement channel, config is None) have no such
-        # signal; there the model itself is the sensor, and a predicted
-        # shortfall against the *new* target is actionable immediately.
-        breached = self._breached
-        predicted_shortfall = False
-        if not breached and self.action is not None and self.action.config is None:
-            breached = predicted_shortfall = (
-                self.action.predicted_capacity < plan_target
-            )
-        # the guards judge the window peak: capacity is acquired ahead of a
-        # forecast rise, and released only when the whole window allows it
-        act, guard = self.guards.decide(plan_target, self._last_target, breached)
-        cause = ""
-        if act:
-            if guard == "breach":
-                cause = "predicted-shortfall" if predicted_shortfall else "measured-sla"
-            elif self.forecaster is not None:
-                # proactive iff the instantaneous target alone would NOT
-                # have produced this same decision — it would have held, or
-                # acted in the other direction (e.g. sensed says release,
-                # the window peak says acquire)
-                act_now, guard_now = self.guards.decide(
-                    target, self._last_target, False
-                )
-                if act_now and guard_now == guard:
-                    cause = "guard"
-                else:
-                    guard = cause = "forecast"
-            else:
-                cause = "guard"
-        if self.action is None:
-            act, guard, cause = True, "bootstrap", "bootstrap"
-        return self._execute(
-            load, target, act, guard,
-            cause=cause, plan_target=plan_target,
-            horizon=horizon, horizon_targets=horizon_targets,
-        )
 
     def run(self, loads: LoadSource) -> list[StepRecord]:
         """Drive the loop over a whole load trace; returns per-step records.
@@ -427,7 +431,8 @@ class ControlLoop:
                 horizon_targets=horizon_targets,
             )
             t0 = time.perf_counter()
-            self.action = self.policy.plan(plan_target, ctx)
+            with span(spans.CONTROL_PLAN):
+                self.action = self.policy.plan(plan_target, ctx)
             plan_s = time.perf_counter() - t0
             self._last_target = plan_target
             # the breach verdict belonged to the replaced deployment; it
@@ -447,18 +452,21 @@ class ControlLoop:
             self._last_achieved = achieved
             self._breached = achieved < self.saturation_threshold * load
             if self.action.config is not None:
-                drift, retrained = self._learn(
-                    self.action.config, load, achieved, getattr(probe, "sim", None)
-                )
+                with span(spans.CONTROL_LEARN):
+                    drift, retrained = self._learn(
+                        self.action.config, load, achieved, getattr(probe, "sim", None)
+                    )
         elif self.action.config is not None:
-            m = self._measure(self.action.config, load)
+            with span(spans.CONTROL_MEASURE):
+                m = self._measure(self.action.config, load)
             if m is not None:
                 achieved, self._last_bottleneck, sim = m
                 self._last_achieved = achieved
                 self._breached = achieved < self.saturation_threshold * load
-                drift, retrained = self._learn(
-                    self.action.config, load, achieved, sim
-                )
+                with span(spans.CONTROL_LEARN):
+                    drift, retrained = self._learn(
+                        self.action.config, load, achieved, sim
+                    )
         else:
             # capacity-model policies (LM): the model is the only sensor; the
             # predicted-shortfall check happens at sense time in step()

@@ -29,7 +29,9 @@ from ..core.dag import Configuration, DagSpec
 from ..core.flow_solver import solve_flow
 from ..core.metrics import STREAM_MANAGER
 from ..core.node_model import oracle_models
+from . import spans
 from .cache import ResultCache
+from .spans import span
 from .simulator import (
     SAMPLES_MODES,
     SimParams,
@@ -369,7 +371,8 @@ class SimulatorEvaluator:
         n_cont = max(c.n_containers for c in configs)
         # structure_for is value-memoized, so this warms the same cache
         # simulate_batch reads — no duplicate structure builds
-        sts = [structure_for(c, self.params) for c in configs]
+        with span(spans.STAGE_STRUCTURE):
+            sts = [structure_for(c, self.params) for c in configs]
         n_edges = max(st.n_edges for st in sts)
         d_max = max(max(st.d_out, st.d_in) for st in sts)
         self._layout_memo[sig] = (tuple(configs), n_inst, n_cont, n_edges, d_max)
@@ -394,55 +397,57 @@ class SimulatorEvaluator:
     def evaluate_batch(
         self, configs: Sequence[Configuration], offered_ktps=OVERLOAD_KTPS
     ) -> list[EvalResult]:
-        configs = list(configs)
-        if not configs:
-            return []
-        if self.sticky_buckets:
-            n_inst, n_cont, n_edges, d_max = self._layout(configs)
-            self._inst_floor = max(self._inst_floor, bucket_size(n_inst))
-            self._cont_floor = max(self._cont_floor, bucket_size(n_cont))
-            if self._backend is None:
-                # pin "auto" on first contact so later batches with different
-                # densities never flip the backend (and recompile)
-                self._backend = resolve_tick_kernel(n_inst, n_edges, "auto")
-            if self._backend == "sparse":
-                self._edge_floor = max(
-                    self._edge_floor, edge_bucket_size(n_edges)
+        with span(spans.EVALUATE):
+            configs = list(configs)
+            if not configs:
+                return []
+            if self.sticky_buckets:
+                n_inst, n_cont, n_edges, d_max = self._layout(configs)
+                self._inst_floor = max(self._inst_floor, bucket_size(n_inst))
+                self._cont_floor = max(self._cont_floor, bucket_size(n_cont))
+                if self._backend is None:
+                    # pin "auto" on first contact so later batches with different
+                    # densities never flip the backend (and recompile)
+                    self._backend = resolve_tick_kernel(n_inst, n_edges, "auto")
+                if self._backend == "sparse":
+                    self._edge_floor = max(
+                        self._edge_floor, edge_bucket_size(n_edges)
+                    )
+                    self._degree_floor = max(
+                        self._degree_floor, degree_bucket_size(d_max)
+                    )
+            if self.sticky_batch:
+                self._batch_floor = max(
+                    self._batch_floor, batch_bucket_size(len(configs))
                 )
-                self._degree_floor = max(
-                    self._degree_floor, degree_bucket_size(d_max)
-                )
-        if self.sticky_batch:
-            self._batch_floor = max(
-                self._batch_floor, batch_bucket_size(len(configs))
+            results = simulate_batch(
+                configs,
+                offered_ktps,
+                duration_s=self.duration_s,
+                params=self.params,
+                min_inst_bucket=self._inst_floor,
+                min_cont_bucket=self._cont_floor,
+                devices=self.devices,
+                min_batch_bucket=self._batch_floor,
+                tick_kernel=self._backend if self._backend else self.tick_kernel,
+                min_edge_bucket=self._edge_floor,
+                min_degree_bucket=self._degree_floor,
+                resident=self.resident_batches,
+                samples=self.samples,
+                dedup=self.dedup,
+                cache=self.result_cache,
+                cache_token=self._cache_token(),
             )
-        results = simulate_batch(
-            configs,
-            offered_ktps,
-            duration_s=self.duration_s,
-            params=self.params,
-            min_inst_bucket=self._inst_floor,
-            min_cont_bucket=self._cont_floor,
-            devices=self.devices,
-            min_batch_bucket=self._batch_floor,
-            tick_kernel=self._backend if self._backend else self.tick_kernel,
-            min_edge_bucket=self._edge_floor,
-            min_degree_bucket=self._degree_floor,
-            resident=self.resident_batches,
-            samples=self.samples,
-            dedup=self.dedup,
-            cache=self.result_cache,
-            cache_token=self._cache_token(),
-        )
-        return [
-            EvalResult(
-                config=c,
-                achieved_ktps=r.achieved_ktps,
-                bottleneck=r.bottleneck_node(self.saturation_threshold),
-                sim=r,
-            )
-            for c, r in zip(configs, results)
-        ]
+            with span(spans.RESULTS):
+                return [
+                    EvalResult(
+                        config=c,
+                        achieved_ktps=r.achieved_ktps,
+                        bottleneck=r.bottleneck_node(self.saturation_threshold),
+                        sim=r,
+                    )
+                    for c, r in zip(configs, results)
+                ]
 
     def evaluate_jobs(
         self, groups: JobGroups, offered_ktps=OVERLOAD_KTPS

@@ -69,6 +69,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..core.dag import Configuration, Grouping
 from ..core.metrics import STREAM_MANAGER, InstanceSamples, MetricsStore
+from . import spans
+from .spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -268,27 +270,12 @@ def _padded_for(
     )
 
 
-def _ndarray_bytes(obj) -> int:
-    """Approximate resident bytes of the numpy arrays hanging off ``obj``
-    (a :class:`SimStructure` or a padded-array dict)."""
-    values = obj.values() if isinstance(obj, dict) else vars(obj).values()
-    return sum(v.nbytes for v in values if isinstance(v, np.ndarray))
-
-
 def structure_cache_info() -> dict:
-    """Host-side structure/padding memoization statistics.
-
-    ``structure_bytes`` / ``padded_bytes`` approximate the resident numpy
-    footprint of the two caches (BENCH extras record them so a perf run
-    shows what stayed resident between calls).
-    """
+    """Host-side structure/padding memoization statistics: entries held
+    and lookups answered (``hits``) or built (``misses``)."""
     return {
         "structures": len(_STRUCTURE_CACHE),
         "padded": len(_PAD_CACHE),
-        "structure_bytes": sum(
-            _ndarray_bytes(v) for v in _STRUCTURE_CACHE.values()
-        ),
-        "padded_bytes": sum(_ndarray_bytes(v) for v in _PAD_CACHE.values()),
         **_STRUCTURE_STATS,
     }
 
@@ -1015,19 +1002,22 @@ def clear_resident_cache() -> None:
 #: Host-transfer accounting for the evaluation path.  ``bytes_full`` /
 #: ``bytes_summary`` count device→host bytes moved by :func:`_run_batch`'s
 #: single per-batch ``jax.device_get`` (split by payload mode);
+#: ``bytes_h2d`` counts host→device bytes it stages (the stacked structure
+#: arrays, unless the resident cache already holds them, plus the per-tick
+#: loads and seeds of every call);
 #: ``refetches`` counts summary-backed results that lazily re-ran full-mode
 #: for trajectory access (learning paths); ``staged_devices`` is the most
 #: distinct devices one batch's staged structure arrays occupied (the shard
 #: count a sharded sweep really reached).  BENCH extras and
 #: :func:`repro.streams.cache.cache_stats` embed this snapshot.
 _TRANSFER_STATS = {
-    "batches": 0, "bytes_full": 0, "bytes_summary": 0, "refetches": 0,
-    "staged_devices": 0,
+    "batches": 0, "bytes_full": 0, "bytes_summary": 0, "bytes_h2d": 0,
+    "refetches": 0, "staged_devices": 0,
 }
 
 
 def transfer_info() -> dict:
-    """Device→host transfer statistics for the evaluation path (see
+    """Host↔device transfer statistics for the evaluation path (see
     ``_TRANSFER_STATS`` for field meanings)."""
     return dict(_TRANSFER_STATS)
 
@@ -1461,62 +1451,63 @@ def simulate_batch(
     if not dedup and cache is None:
         return run(list(range(B)), tick_kernel)
 
-    # Tier 1: collapse value-identical rows before padding/stacking.
-    row_keys = [
-        (c, _canonical_load(o), int(s))
-        for c, o, s in zip(configs, offered_list, seeds)
-    ]
-    if dedup:
-        first: dict = {}
-        uniq: list[int] = []
-        row_of: list[int] = []
-        for i, k in enumerate(row_keys):
-            j = first.get(k)
-            if j is None:
-                j = len(uniq)
-                first[k] = j
-                uniq.append(i)
-            row_of.append(j)
-    else:
-        uniq = list(range(B))
-        row_of = list(range(B))
-    _DEDUP_STATS["batches"] += 1
-    _DEDUP_STATS["rows_in"] += B
-    _DEDUP_STATS["rows_unique"] += len(uniq)
-
-    results_u: list = [None] * len(uniq)
-    backend = tick_kernel
-    full_keys = None
-    if cache is not None:
-        # the backend is resolved from the unique rows' unpadded maxima —
-        # identical to the full set's (duplicates share structures) — and
-        # pinned for the executed subset, so key-backend == run-backend
-        # even when cache hits remove the densest row
-        sts = [structure_for(configs[i], params) for i in uniq]
-        backend = resolve_tick_kernel(
-            max(st.n_inst for st in sts),
-            max(st.n_edges for st in sts),
-            tick_kernel,
-        )
-        # the key carries the payload mode: a summary entry must never
-        # answer a full-mode lookup (nor vice versa) — the payloads differ;
-        # and the platform: backends agree only to a tolerance, so a CPU
-        # reference must never be answered from accelerator results
-        platform = _platform()
-        full_keys = [
-            row_keys[i] + (params, n_ticks, backend, samples, cache_token,
-                           platform)
-            for i in uniq
+    with span(spans.DEDUP):
+        # Tier 1: collapse value-identical rows before padding/stacking.
+        row_keys = [
+            (c, _canonical_load(o), int(s))
+            for c, o, s in zip(configs, offered_list, seeds)
         ]
-        miss = []
-        for j, key in enumerate(full_keys):
-            hit = cache.get(key)
-            if hit is None:
-                miss.append(j)
-            else:
-                results_u[j] = hit
-    else:
-        miss = list(range(len(uniq)))
+        if dedup:
+            first: dict = {}
+            uniq: list[int] = []
+            row_of: list[int] = []
+            for i, k in enumerate(row_keys):
+                j = first.get(k)
+                if j is None:
+                    j = len(uniq)
+                    first[k] = j
+                    uniq.append(i)
+                row_of.append(j)
+        else:
+            uniq = list(range(B))
+            row_of = list(range(B))
+        _DEDUP_STATS["batches"] += 1
+        _DEDUP_STATS["rows_in"] += B
+        _DEDUP_STATS["rows_unique"] += len(uniq)
+
+        results_u: list = [None] * len(uniq)
+        backend = tick_kernel
+        full_keys = None
+        if cache is not None:
+            # the backend is resolved from the unique rows' unpadded maxima —
+            # identical to the full set's (duplicates share structures) — and
+            # pinned for the executed subset, so key-backend == run-backend
+            # even when cache hits remove the densest row
+            sts = [structure_for(configs[i], params) for i in uniq]
+            backend = resolve_tick_kernel(
+                max(st.n_inst for st in sts),
+                max(st.n_edges for st in sts),
+                tick_kernel,
+            )
+            # the key carries the payload mode: a summary entry must never
+            # answer a full-mode lookup (nor vice versa) — the payloads differ;
+            # and the platform: backends agree only to a tolerance, so a CPU
+            # reference must never be answered from accelerator results
+            platform = _platform()
+            full_keys = [
+                row_keys[i] + (params, n_ticks, backend, samples, cache_token,
+                               platform)
+                for i in uniq
+            ]
+            miss = []
+            for j, key in enumerate(full_keys):
+                hit = cache.get(key)
+                if hit is None:
+                    miss.append(j)
+                else:
+                    results_u[j] = hit
+        else:
+            miss = list(range(len(uniq)))
 
     _DEDUP_STATS["rows_executed"] += len(miss)
     if miss:
@@ -1600,7 +1591,8 @@ def _run_batch(
     B = len(configs)
     B_bucket = batch_bucket_size(B, min_batch_bucket) if min_batch_bucket else B
     n_dev = shard_count(B_bucket, devices)
-    structures = [structure_for(c, params) for c in configs]
+    with span(spans.STAGE_STRUCTURE):
+        structures = [structure_for(c, params) for c in configs]
     n_inst_b = bucket_size(max(st.n_inst for st in structures), min_inst_bucket)
     n_cont_b = bucket_size(max(st.n_cont for st in structures), min_cont_bucket)
     backend = resolve_tick_kernel(
@@ -1620,7 +1612,8 @@ def _run_batch(
             max(st.d_in for st in structures), min_degree_bucket
         )
 
-    per_tick = np.stack([_per_tick_trace(o, n_ticks, params.dt) for o in offered_list])
+    with span(spans.STAGE_STACK):
+        per_tick = np.stack([_per_tick_trace(o, n_ticks, params.dt) for o in offered_list])
 
     # pad the batch axis: up to the batch bucket (if any), then to a multiple
     # of the shard count, by replicating the last row (replicas are sliced
@@ -1649,22 +1642,26 @@ def _run_batch(
         else:
             _RESIDENT_STATS["misses"] += 1
     if stacked_dev is None:
-        padded = [
-            _padded_for(st, params, n_inst_b, n_cont_b, n_edge_b, d_out_b, d_in_b)
-            for st in structures
-        ]
-        stacked = {k: np.stack([p[k] for p in padded]) for k in padded[0]}
-        if fill or n_dev > 1:
-            stacked = {k: shard(v) for k, v in stacked.items()}
-        if n_dev > 1:
-            # place each shard on its pmap device up front — a resident hit
-            # then re-enters pmap with zero host→device transfers
-            mesh = Mesh(np.array(jax.local_devices()[:n_dev]), ("shard",))
-            stacked_dev = jax.device_put(
-                stacked, NamedSharding(mesh, PartitionSpec("shard"))
-            )
-        else:
-            stacked_dev = {k: jnp.asarray(v) for k, v in stacked.items()}
+        with span(spans.STAGE_PAD):
+            padded = [
+                _padded_for(st, params, n_inst_b, n_cont_b, n_edge_b, d_out_b, d_in_b)
+                for st in structures
+            ]
+        with span(spans.STAGE_STACK):
+            stacked = {k: np.stack([p[k] for p in padded]) for k in padded[0]}
+            if fill or n_dev > 1:
+                stacked = {k: shard(v) for k, v in stacked.items()}
+        with span(spans.STAGE_H2D):
+            if n_dev > 1:
+                # place each shard on its pmap device up front — a resident
+                # hit then re-enters pmap with zero host→device transfers
+                mesh = Mesh(np.array(jax.local_devices()[:n_dev]), ("shard",))
+                stacked_dev = jax.device_put(
+                    stacked, NamedSharding(mesh, PartitionSpec("shard"))
+                )
+            else:
+                stacked_dev = {k: jnp.asarray(v) for k, v in stacked.items()}
+        _TRANSFER_STATS["bytes_h2d"] += sum(int(v.nbytes) for v in stacked.values())
         if stage_key is not None:
             _resident_put(stage_key, stacked_dev)
     _TRANSFER_STATS["staged_devices"] = max(
@@ -1672,84 +1669,99 @@ def _run_batch(
         len(stacked_dev["busy_cost"].devices()),
     )
 
-    per_tick_in = np.asarray(per_tick, np.float32)
-    seeds_in = np.asarray(seeds, np.int32)
-    if fill or n_dev > 1:
-        per_tick_in = shard(per_tick_in)
-        seeds_in = shard(seeds_in)
+    with span(spans.STAGE_STACK):
+        per_tick_in = np.asarray(per_tick, np.float32)
+        seeds_in = np.asarray(seeds, np.int32)
+        if fill or n_dev > 1:
+            per_tick_in = shard(per_tick_in)
+            seeds_in = shard(seeds_in)
 
+    compiles = _CACHE_STATS["misses"]
     kernel = _get_batch_kernel(
         per_dev_B, n_inst_b, n_cont_b, n_ticks, params.sample_every, n_dev,
         backend, n_edge_b or 0, d_out_b or 0, d_in_b or 0,
         donate_batch=not resident, samples_mode=samples_mode,
     )
-    out = kernel(
-        stacked_dev,
-        jnp.asarray(per_tick_in),
-        jnp.asarray(seeds_in),
-        params.dt,
-        params.noise_std,
-        params.queue_high_ktuples,
-        params.queue_low_ktuples,
-        params.gc_heap_mb,
-        params.gc_cost_frac,
-        params.mem_alloc_mb_per_ktuple,
-    )
+    with span(spans.STAGE_H2D):
+        per_tick_dev = jnp.asarray(per_tick_in)
+        seeds_dev = jnp.asarray(seeds_in)
+    _TRANSFER_STATS["bytes_h2d"] += int(per_tick_in.nbytes) + int(seeds_in.nbytes)
+    # a call right after a compile-cache miss traces and compiles first
+    with span(spans.KERNEL_COMPILE if _CACHE_STATS["misses"] > compiles
+              else spans.KERNEL_DISPATCH):
+        out = kernel(
+            stacked_dev,
+            per_tick_dev,
+            seeds_dev,
+            params.dt,
+            params.noise_std,
+            params.queue_high_ktuples,
+            params.queue_low_ktuples,
+            params.gc_heap_mb,
+            params.gc_cost_frac,
+            params.mem_alloc_mb_per_ktuple,
+        )
+    # waits no longer than the device_get below would: it splits the
+    # kernel's device time from the transfer back
+    with span(spans.KERNEL_WAIT):
+        jax.block_until_ready(out)
     # ONE device→host transfer for the whole batch pytree — O(B·S·I) bytes
     # of trajectories in full mode, O(B·I) of summaries in summary mode
-    out = jax.device_get(out)
+    with span(spans.STAGE_D2H):
+        out = jax.device_get(out)
     _TRANSFER_STATS["batches"] += 1
     _TRANSFER_STATS[
         "bytes_summary" if samples_mode == "summary" else "bytes_full"
     ] += sum(int(v.nbytes) for v in jax.tree_util.tree_leaves(out))
-    if n_dev > 1:
-        # merge the device axis back and drop the fill replicas
-        out = {k: v.reshape(-1, *v.shape[2:])[:B] for k, v in out.items()}
-    else:
-        out = {k: v[:B] for k, v in out.items()}
+    with span(spans.RESULTS):
+        if n_dev > 1:
+            # merge the device axis back and drop the fill replicas
+            out = {k: v.reshape(-1, *v.shape[2:])[:B] for k, v in out.items()}
+        else:
+            out = {k: v[:B] for k, v in out.items()}
 
-    n_samples = n_ticks // params.sample_every
-    results: list[SimResult] = []
-    for i, st in enumerate(structures):
-        off = (
-            per_tick[i, : n_samples * params.sample_every]
-            .reshape(n_samples, -1)
-            .mean(1)
-            / params.dt
-        )
-        if samples_mode == "summary":
-            summary = dict(
-                src_half_mean=out["src_half_mean"][i],
-                caputil_half_mean=out["caputil_half_mean"][i][: st.n_inst],
-                sm_half_mean=out["sm_half_mean"][i][: st.n_cont],
-                bp_half_mean=out["bp_half_mean"][i][: st.n_inst],
-                mem_peak=out["mem_peak"][i][: st.n_inst],
-                gate_final=out["gate_final"][i],
+        n_samples = n_ticks // params.sample_every
+        results: list[SimResult] = []
+        for i, st in enumerate(structures):
+            off = (
+                per_tick[i, : n_samples * params.sample_every]
+                .reshape(n_samples, -1)
+                .mean(1)
+                / params.dt
             )
-            results.append(
-                SimResult(
-                    structure=st, params=params, offered_ktps=off,
-                    summary=summary, mode="summary",
-                    refetch=_make_refetch(
-                        configs[i], offered_list[i], seeds[i], n_ticks,
-                        params, backend,
-                    ),
+            if samples_mode == "summary":
+                summary = dict(
+                    src_half_mean=out["src_half_mean"][i],
+                    caputil_half_mean=out["caputil_half_mean"][i][: st.n_inst],
+                    sm_half_mean=out["sm_half_mean"][i][: st.n_cont],
+                    bp_half_mean=out["bp_half_mean"][i][: st.n_inst],
+                    mem_peak=out["mem_peak"][i][: st.n_inst],
+                    gate_final=out["gate_final"][i],
                 )
+                results.append(
+                    SimResult(
+                        structure=st, params=params, offered_ktps=off,
+                        summary=summary, mode="summary",
+                        refetch=_make_refetch(
+                            configs[i], offered_list[i], seeds[i], n_ticks,
+                            params, backend,
+                        ),
+                    )
+                )
+                continue
+            si: dict = {}
+            for k, v in out.items():
+                vi = v[i]
+                if vi.ndim == 1:                      # per-run scalar series (gate)
+                    si[k] = vi
+                elif k in ("sm_trav", "sm_cpu"):      # per-container series
+                    si[k] = vi[:, : st.n_cont]
+                else:                                 # per-instance series
+                    si[k] = vi[:, : st.n_inst]
+            results.append(
+                SimResult(structure=st, params=params, offered_ktps=off, samples=si)
             )
-            continue
-        si: dict = {}
-        for k, v in out.items():
-            vi = v[i]
-            if vi.ndim == 1:                      # per-run scalar series (gate)
-                si[k] = vi
-            elif k in ("sm_trav", "sm_cpu"):      # per-container series
-                si[k] = vi[:, : st.n_cont]
-            else:                                 # per-instance series
-                si[k] = vi[:, : st.n_inst]
-        results.append(
-            SimResult(structure=st, params=params, offered_ktps=off, samples=si)
-        )
-    return results
+        return results
 
 
 def _grid_through_batch(evaluate_batch, configs, rates_ktps):

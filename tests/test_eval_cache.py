@@ -430,9 +430,8 @@ def test_cache_stats_shape():
     assert {"batches", "rows_in", "rows_unique", "rows_executed"} <= set(
         stats["dedup"]
     )
-    assert {"batches", "bytes_full", "bytes_summary", "refetches"} <= set(
-        stats["transfer"]
-    )
+    assert {"batches", "bytes_full", "bytes_summary", "bytes_h2d",
+            "refetches"} <= set(stats["transfer"])
 
 
 def test_steady_trace_reaches_high_hit_rate():
