@@ -35,7 +35,7 @@ from repro.streams import (
     resident_cache_info,
     simulate_batch,
 )
-from repro.streams.simulator import pad_structure, structure_for
+from repro.streams.simulator import _expand_routing, pad_structure, structure_for
 
 from .common import EXTRAS, emit, timed
 
@@ -99,8 +99,9 @@ def _flow_step() -> dict:
     C = jnp.asarray(
         (dense["cont_of"][:, None] == np.arange(K)[None, :]).astype(np.float32)
     )
-    W = jnp.asarray(dense["W"])
-    remote = jnp.asarray(dense["remote"])
+    W, remote = _expand_routing(
+        dense["W_node"], dense["node_of"], dense["cont_of"], dense["inst_mask"]
+    )
     rowsum = W.sum(axis=1)
 
     @jax.jit

@@ -14,6 +14,7 @@ from .workloads import (
 from .simulator import (
     DEGREE_LADDER,
     EDGE_LADDER,
+    NODE_LADDER,
     SAMPLES_MODES,
     SimParams,
     SimResult,
@@ -30,6 +31,7 @@ from .simulator import (
     edge_bucket_size,
     kernel_cache_info,
     measure_capacity,
+    node_bucket_size,
     pad_structure,
     resident_cache_info,
     resolve_tick_kernel,
@@ -61,7 +63,7 @@ from . import sources
 
 __all__ = [
     "DEGREE_LADDER",
-    "EDGE_LADDER", "SAMPLES_MODES", "WORKLOADS", "ConfigEvaluator",
+    "EDGE_LADDER", "NODE_LADDER", "SAMPLES_MODES", "WORKLOADS", "ConfigEvaluator",
     "EvalResult",
     "ExecutorEvaluator",
     "OVERLOAD_KTPS", "PerCandidateLoads", "ResultCache", "SimParams",
@@ -75,7 +77,8 @@ __all__ = [
     "degree_bucket_size",
     "diamond", "edge_bucket_size", "evaluate_grid_with", "evaluate_jobs_with",
     "kernel_cache_info", "measure_capacity", "mobile_analytics",
-    "pad_structure", "resident_cache_info", "resolve_tick_kernel",
+    "node_bucket_size", "pad_structure", "resident_cache_info",
+    "resolve_tick_kernel",
     "result_cache_info",
     "shard_count", "simulate", "simulate_batch",
     "simulate_grid", "sources", "structure_cache_info", "training_sweep",

@@ -42,6 +42,7 @@ from .simulator import (
     degree_bucket_size,
     edge_bucket_size,
     is_scalar_load,
+    node_bucket_size,
     resolve_tick_kernel,
     simulate_batch,
     structure_for,
@@ -332,6 +333,7 @@ class SimulatorEvaluator:
         self._batch_floor = 0
         self._edge_floor = 0
         self._degree_floor = 0
+        self._node_floor = 0
         self._backend: str | None = None if tick_kernel == "auto" else tick_kernel
         # shape-scan memo: flat config tuple (by identity) -> bucket inputs;
         # the fleet scheduler re-submits largely identical candidate lists
@@ -357,16 +359,19 @@ class SimulatorEvaluator:
                 self._degree_floor, degree_bucket_size(max_degree)
             )
 
-    def _layout(self, configs: list[Configuration]) -> tuple[int, int, int, int]:
-        """Max (instances, containers, edges, in-/out-degree) across
-        ``configs`` — memoized on the identity signature of the batch so
-        repeated submissions of the same candidate layout (fleet replans)
-        skip the packing re-scan."""
+    def _layout(
+        self, configs: list[Configuration]
+    ) -> tuple[int, int, int, int, int]:
+        """Max (instances, containers, edges, in-/out-degree, DAG nodes)
+        across ``configs`` — memoized on the identity signature of the batch
+        so repeated submissions of the same candidate layout (fleet
+        replans) skip the packing re-scan.  Edge counts and degrees are the
+        structures' closed forms: no host (I, I) routing is built."""
         sig = tuple(id(c) for c in configs)
         hit = self._layout_memo.get(sig)
         if hit is not None:
             self._layout_memo.move_to_end(sig)
-            return hit[1], hit[2], hit[3], hit[4]
+            return hit[1:]
         n_inst = max(sum(len(p) for p in c.packing) for c in configs)
         n_cont = max(c.n_containers for c in configs)
         # structure_for is value-memoized, so this warms the same cache
@@ -375,10 +380,12 @@ class SimulatorEvaluator:
             sts = [structure_for(c, self.params) for c in configs]
         n_edges = max(st.n_edges for st in sts)
         d_max = max(max(st.d_out, st.d_in) for st in sts)
-        self._layout_memo[sig] = (tuple(configs), n_inst, n_cont, n_edges, d_max)
+        n_nodes = max(st.n_nodes for st in sts)
+        layout = (n_inst, n_cont, n_edges, d_max, n_nodes)
+        self._layout_memo[sig] = (tuple(configs), *layout)
         if len(self._layout_memo) > 128:
             self._layout_memo.popitem(last=False)
-        return n_inst, n_cont, n_edges, d_max
+        return layout
 
     def _cache_token(self):
         """Invalidation token folded into every result-cache key: the
@@ -402,7 +409,7 @@ class SimulatorEvaluator:
             if not configs:
                 return []
             if self.sticky_buckets:
-                n_inst, n_cont, n_edges, d_max = self._layout(configs)
+                n_inst, n_cont, n_edges, d_max, n_nodes = self._layout(configs)
                 self._inst_floor = max(self._inst_floor, bucket_size(n_inst))
                 self._cont_floor = max(self._cont_floor, bucket_size(n_cont))
                 if self._backend is None:
@@ -415,6 +422,10 @@ class SimulatorEvaluator:
                     )
                     self._degree_floor = max(
                         self._degree_floor, degree_bucket_size(d_max)
+                    )
+                else:
+                    self._node_floor = max(
+                        self._node_floor, node_bucket_size(n_nodes)
                     )
             if self.sticky_batch:
                 self._batch_floor = max(
@@ -432,6 +443,7 @@ class SimulatorEvaluator:
                 tick_kernel=self._backend if self._backend else self.tick_kernel,
                 min_edge_bucket=self._edge_floor,
                 min_degree_bucket=self._degree_floor,
+                min_node_bucket=self._node_floor,
                 resident=self.resident_batches,
                 samples=self.samples,
                 dedup=self.dedup,

@@ -59,7 +59,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import OrderedDict
-from functools import partial
+from functools import cached_property, partial
 from typing import Sequence
 
 import jax
@@ -91,9 +91,23 @@ class SimParams:
     seed: int = 0
 
 
+def _routing_view(key: str, doc: str) -> property:
+    """A read-only view of one array of ``SimStructure._routing``."""
+    return property(lambda st: st._routing[key], doc=doc)
+
+
 @dataclasses.dataclass
 class SimStructure:
-    """Static arrays describing one configuration (host-side, numpy)."""
+    """Static arrays describing one configuration (host-side, numpy).
+
+    Routing is held at node granularity: ``W_node[a, b]`` is the weight of
+    every (instance of ``a``, instance of ``b``) pair, so the instance-level
+    ``W`` is ``W_node[np.ix_(node_of, node_of)]`` and ``remote`` is
+    ``cont_of[i] != cont_of[j]``.  The dense tick kernel expands both on the
+    device; the host forms (``W``, ``remote`` and the edge list) are built
+    on first use, for the sparse layout and for inspection, and counted in
+    ``structure_cache_info()["dense_expansions"]``.
+    """
 
     config: Configuration
     n_inst: int
@@ -106,34 +120,55 @@ class SimStructure:
     gamma: np.ndarray            # (n_inst,)
     mem_base: np.ndarray         # (n_inst,) MB
     mem_slope: np.ndarray        # (n_inst,) MB per ktps
-    W: np.ndarray                # (n_inst, n_inst) routing weights (copies per output tuple)
-    remote: np.ndarray           # (n_inst, n_inst) bool, cross-container
+    W_node: np.ndarray           # (n_nodes, n_nodes) routing weight per node pair
     cont_cpus: np.ndarray        # (n_cont,)
     cont_mem: np.ndarray         # (n_cont,)
     sm_cost_eff: np.ndarray      # (n_cont,) per-traversal SM cost incl. fan-out overhead
-    rowsum_W: np.ndarray         # (n_inst,)
     node_names: list[str]
-    #: CSR-like edge list — the nonzeros of ``W`` in row-major order.  The
-    #: sparse tick kernel scales with these instead of the (I, I) matrices.
-    edge_src: np.ndarray         # (n_edges,) int32 source instance
-    edge_dst: np.ndarray         # (n_edges,) int32 destination instance
-    edge_w: np.ndarray           # (n_edges,) routing weight W[src, dst]
-    edge_remote: np.ndarray      # (n_edges,) bool, cross-container edge
-    n_edges: int
+    n_edges: int                 # nonzeros of ``W``
     d_out: int                   # max out-degree (edges per source instance)
     d_in: int                    # max in-degree (edges per dest instance)
+
+    @property
+    def n_nodes(self) -> int:
+        return len(self.node_names)
+
+    @cached_property
+    def _routing(self) -> dict:
+        """The host (I, I) routing and its CSR-like edge list — the nonzeros
+        of ``W`` in row-major order, which the sparse tick kernel scales
+        with.  Built once per structure, on first use."""
+        _STRUCTURE_STATS["dense_expansions"] += 1
+        W = self.W_node[np.ix_(self.node_of, self.node_of)]   # C-ordered
+        remote = self.cont_of[:, None] != self.cont_of[None, :]
+        edge_src, edge_dst = (x.astype(np.int32) for x in np.nonzero(W))
+        return dict(
+            W=W, remote=remote, edge_src=edge_src, edge_dst=edge_dst,
+            edge_w=W[edge_src, edge_dst], edge_remote=remote[edge_src, edge_dst],
+        )
+
+    W = _routing_view("W", "(n_inst, n_inst) routing weights (copies per output tuple).")
+    remote = _routing_view("remote", "(n_inst, n_inst) bool, cross-container.")
+    edge_src = _routing_view("edge_src", "(n_edges,) int32 source instance.")
+    edge_dst = _routing_view("edge_dst", "(n_edges,) int32 destination instance.")
+    edge_w = _routing_view("edge_w", "(n_edges,) routing weight ``W[src, dst]``.")
+    edge_remote = _routing_view("edge_remote", "(n_edges,) bool, cross-container edge.")
 
 
 def build_structure(config: Configuration, params: SimParams) -> SimStructure:
     dag = config.dag
-    instances = config.instances()
-    n_inst = len(instances)
+    # instances in ``config.instances()`` order: container-major, slot-minor
+    per_cont = [len(c) for c in config.packing]
+    n_inst = sum(per_cont)
     n_cont = config.n_containers
+    n_nodes = len(dag.node_names)
     name_to_idx = {n: i for i, n in enumerate(dag.node_names)}
-    node_of = np.array([name_to_idx[nm] for nm, _c, _s in instances], np.int32)
-    cont_of = np.array([c for _n, c, _s in instances], np.int32)
+    node_of = np.fromiter(
+        (name_to_idx[nm] for c in config.packing for nm in c), np.int32, n_inst
+    )
+    cont_of = np.repeat(np.arange(n_cont, dtype=np.int32), per_cont)
     src_names = {s.name for s in dag.sources()}
-    is_source = np.array([nm in src_names for nm, _c, _s in instances])
+    is_source = np.array([nm in src_names for nm in dag.node_names], bool)[node_of]
 
     # per-NODE cost vectors gathered onto instances by ``node_of`` fancy
     # indexing — O(nodes + instances) instead of an attribute-access loop
@@ -148,33 +183,32 @@ def build_structure(config: Configuration, params: SimParams) -> SimStructure:
     mem_base = np.array([s.mem_mb_base for s in node_specs])[node_of]
     mem_slope = np.array([s.mem_mb_per_ktps for s in node_specs])[node_of]
 
-    inst_of_node: dict[str, list[int]] = {}
-    for i, (nm, _c, _s) in enumerate(instances):
-        inst_of_node.setdefault(nm, []).append(i)
-
-    # routing weights: one block-add per DAG edge (``np.ix_`` outer index)
-    # replaces the O(|ups|·|downs|) Python inner loops.  Accumulation stays
-    # edge-major exactly like the loop form, so repeated edges between the
-    # same node pair sum in the same order — bitwise-identical W.
-    W = np.zeros((n_inst, n_inst))
+    # routing weights per node pair, accumulated edge-major from 0.0: every
+    # instance pair of a node pair receives the same sequence of adds, so
+    # ``W_node[np.ix_(node_of, node_of)]`` is bitwise the instance-level W
+    n_of_node = np.bincount(node_of, minlength=n_nodes)
+    W_node = np.zeros((n_nodes, n_nodes))
     for e in dag.edges:
-        ups = inst_of_node.get(e.src, [])
-        downs = inst_of_node.get(e.dst, [])
-        if not ups or not downs:
+        a, b = name_to_idx[e.src], name_to_idx[e.dst]
+        if not n_of_node[a] or not n_of_node[b]:
             raise ValueError(f"edge {e.src}->{e.dst} lacks instances")
-        w = 1.0 if e.grouping is Grouping.ALL else 1.0 / len(downs)
-        W[np.ix_(ups, downs)] += w
-    remote = cont_of[:, None] != cont_of[None, :]
-    edge_src, edge_dst = (x.astype(np.int32) for x in np.nonzero(W))
+        W_node[a, b] += 1.0 if e.grouping is Grouping.ALL else 1.0 / n_of_node[b]
+
+    # edge counts in closed form: a nonzero node pair (a, b) routes every
+    # instance of ``a`` to every instance of ``b``
+    linked = (W_node != 0).astype(np.int64)
+    present = n_of_node > 0
+    out_deg = linked @ n_of_node
+    in_deg = linked.T @ n_of_node
 
     # fan-out overhead: number of distinct remote peer containers each SM
-    # talks to.  Vectorized over the routing edges: a cross-container edge
-    # connects its endpoints' containers (both directions count as peers),
-    # so the peer count is a row-sum of the symmetrized container-pair
-    # connectivity matrix — no O(containers · instances²) scan.
-    conn = np.zeros((n_cont, n_cont), bool)
-    cross = cont_of[edge_src] != cont_of[edge_dst]
-    conn[cont_of[edge_src[cross]], cont_of[edge_dst[cross]]] = True
+    # talks to.  Two containers are peers when they hold instances of a
+    # linked node pair: the outer product of the nodes' container sets,
+    # without the diagonal (same-container flows cross no SM), symmetrized.
+    on_cont = np.zeros((n_nodes, n_cont), np.int64)
+    on_cont[node_of, cont_of] = 1
+    conn = (on_cont.T @ linked @ on_cont) > 0
+    np.fill_diagonal(conn, False)
     n_peers = (conn | conn.T).sum(axis=1)
     sm_cost_eff = params.sm_cost_per_ktuple * (
         1.0 + params.sm_fanout_coef * n_peers
@@ -191,22 +225,14 @@ def build_structure(config: Configuration, params: SimParams) -> SimStructure:
         gamma=gamma,
         mem_base=mem_base,
         mem_slope=mem_slope,
-        W=W,
-        remote=remote,
+        W_node=W_node,
         cont_cpus=np.array([d.cpus for d in config.dims]),
         cont_mem=np.array([d.mem_mb for d in config.dims]),
         sm_cost_eff=sm_cost_eff,
-        rowsum_W=W.sum(axis=1),
         node_names=list(dag.node_names),
-        edge_src=edge_src,
-        edge_dst=edge_dst,
-        edge_w=W[edge_src, edge_dst],
-        edge_remote=remote[edge_src, edge_dst],
-        n_edges=int(edge_src.shape[0]),
-        d_out=int(np.bincount(edge_src, minlength=n_inst).max())
-        if edge_src.size else 0,
-        d_in=int(np.bincount(edge_dst, minlength=n_inst).max())
-        if edge_dst.size else 0,
+        n_edges=int(n_of_node @ linked @ n_of_node),
+        d_out=int(out_deg[present].max(initial=0)),
+        d_in=int(in_deg[present].max(initial=0)),
     )
 
 
@@ -215,15 +241,17 @@ def build_structure(config: Configuration, params: SimParams) -> SimStructure:
 # ---------------------------------------------------------------------------
 
 #: ``build_structure`` is pure in ``(config, params)`` — both are frozen
-#: (hashable-by-value) dataclasses — and its O(instances²) host-side loops
-#: dominate repeated evaluation of recurring configurations (the fleet
-#: scheduler re-scores largely the same candidate ladder every replan).
-#: Bounded LRU keyed by value, so two distinct-but-equal Configuration
-#: objects share one structure.
+#: (hashable-by-value) dataclasses — and recurring configurations (the
+#: fleet scheduler re-scores largely the same candidate ladder every
+#: replan) skip its per-instance host work.  Bounded LRU keyed by value, so
+#: two distinct-but-equal Configuration objects share one structure.
+#: ``dense_expansions`` counts host (I, I) routing builds (``SimStructure``'s
+#: ``W`` / ``remote`` / edge list): the sparse layout needs them, the dense
+#: one never does.
 _STRUCTURE_CACHE: "OrderedDict[tuple, SimStructure]" = OrderedDict()
 _PAD_CACHE: "OrderedDict[tuple, dict]" = OrderedDict()
 _STRUCTURE_CACHE_MAX = 4096
-_STRUCTURE_STATS = {"hits": 0, "misses": 0}
+_STRUCTURE_STATS = {"hits": 0, "misses": 0, "dense_expansions": 0}
 
 
 def _lru_get(cache: OrderedDict, key, build):
@@ -255,6 +283,7 @@ def _padded_for(
     n_edge_bucket: int | None = None,
     d_out_bucket: int | None = None,
     d_in_bucket: int | None = None,
+    n_node_bucket: int | None = None,
 ) -> dict:
     """Memoized :func:`pad_structure` — the bucket layout for one config.
 
@@ -264,15 +293,16 @@ def _padded_for(
     return _lru_get(
         _PAD_CACHE,
         (st.config, params, n_inst_bucket, n_cont_bucket, n_edge_bucket,
-         d_out_bucket, d_in_bucket),
+         d_out_bucket, d_in_bucket, n_node_bucket),
         lambda: pad_structure(st, n_inst_bucket, n_cont_bucket, n_edge_bucket,
-                              d_out_bucket, d_in_bucket),
+                              d_out_bucket, d_in_bucket, n_node_bucket),
     )
 
 
 def structure_cache_info() -> dict:
-    """Host-side structure/padding memoization statistics: entries held
-    and lookups answered (``hits``) or built (``misses``)."""
+    """Host-side structure/padding memoization statistics: entries held,
+    lookups answered (``hits``) or built (``misses``), and host (I, I)
+    routing materializations (``dense_expansions``)."""
     return {
         "structures": len(_STRUCTURE_CACHE),
         "padded": len(_PAD_CACHE),
@@ -283,8 +313,8 @@ def structure_cache_info() -> dict:
 def clear_structure_cache() -> None:
     _STRUCTURE_CACHE.clear()
     _PAD_CACHE.clear()
-    _STRUCTURE_STATS["hits"] = 0
-    _STRUCTURE_STATS["misses"] = 0
+    for k in _STRUCTURE_STATS:
+        _STRUCTURE_STATS[k] = 0
 
 
 # ---------------------------------------------------------------------------
@@ -357,6 +387,21 @@ def degree_bucket_size(n: int, floor: int = 0) -> int:
     return -(-n // DEGREE_LADDER[-1]) * DEGREE_LADDER[-1]
 
 
+#: Ladder for the *node* axis of the dense kernel's node-pair routing
+#: table.  Most DAGs have at most 8 nodes, so one rung covers a fleet's
+#: mixed DAGs; the table is a few hundred bytes a row at any rung.
+NODE_LADDER = (8, 32, 128)
+
+
+def node_bucket_size(n: int, floor: int = 0) -> int:
+    """Round a DAG's node count up to the node ladder (``floor`` is sticky)."""
+    n = max(int(n), int(floor), 1)
+    for b in NODE_LADDER:
+        if n <= b:
+            return b
+    return -(-n // NODE_LADDER[-1]) * NODE_LADDER[-1]
+
+
 #: ``tick_kernel="auto"`` picks the sparse kernel when the densest
 #: structure in the batch has edge density ``E / I²`` below this.  The
 #: margin (vs the naive 1.0 crossover) pays for the sparse path's
@@ -408,6 +453,7 @@ def pad_structure(
     n_edge_bucket: int | None = None,
     d_out_bucket: int | None = None,
     d_in_bucket: int | None = None,
+    n_node_bucket: int | None = None,
 ) -> dict:
     """Pad a :class:`SimStructure` to static bucket shapes.
 
@@ -418,8 +464,14 @@ def pad_structure(
     always occupy the leading positions, so per-config metrics are recovered
     by slicing ``[: n_inst]`` / ``[: n_cont]``.
 
-    ``n_edge_bucket=None`` (default) lays out the **dense** kernel's arrays
-    — the ``(I, I)`` routing/remote matrices.  An integer instead lays out
+    ``n_edge_bucket=None`` (default) lays out the **dense** kernel's arrays:
+    ``node_of`` (I,) and the node-pair routing table ``W_node`` (Nb, Nb)
+    in float32, zero-padded to the node bucket ``n_node_bucket`` (default:
+    the structure's own :data:`NODE_LADDER` rung).  The kernel expands the
+    ``(I, I)`` routing/remote matrices from them and ``cont_of`` on the
+    device, so O(I + Nb²) bytes a row cross to it; padded instances' node
+    ids are arbitrary, since ``inst_mask`` zeroes their routing.  An
+    integer ``n_edge_bucket`` instead lays out
     the **sparse** kernel's padded edge list (``edge_src`` / ``edge_dst`` /
     ``edge_share`` / ``edge_remote`` / container ids / ``edge_mask``) plus
     the ELL row-gather matrices ``ell_src`` (I, d_out) / ``ell_dst``
@@ -462,11 +514,15 @@ def pad_structure(
         cont_mask=cont_mask,
     )
     if n_edge_bucket is None:
-        W = np.zeros((I, I), np.float32)
-        W[: st.n_inst, : st.n_inst] = st.W
-        remote = np.zeros((I, I), bool)
-        remote[: st.n_inst, : st.n_inst] = st.remote
-        arrays.update(W=W, remote=remote)
+        Nb = (int(n_node_bucket) if n_node_bucket is not None
+              else node_bucket_size(st.n_nodes))
+        if Nb < st.n_nodes:
+            raise ValueError(
+                f"node bucket {Nb} smaller than structure ({st.n_nodes} nodes)"
+            )
+        W_node = np.zeros((Nb, Nb), np.float32)
+        W_node[: st.n_nodes, : st.n_nodes] = st.W_node
+        arrays.update(node_of=pad1(st.node_of, I, 0, np.int32), W_node=W_node)
         return arrays
 
     E = int(n_edge_bucket)
@@ -610,6 +666,24 @@ def _host_summary(samples: dict, is_source: np.ndarray) -> dict:
     return {k: np.asarray(v) for k, v in jax.device_get(out).items()}
 
 
+def _expand_routing(W_node, node_of, cont_of, inst_mask):
+    """The dense kernel's (I, I) routing weights and cross-container mask,
+    expanded from the node-pair table once per row, before the tick scan:
+    ``W[i, j] = W_node[node_of[i], node_of[j]]`` and ``remote[i, j] =
+    cont_of[i] != cont_of[j]``, both zero wherever ``i`` or ``j`` is a
+    padded instance.  Exact: each lookup is a select-sum over the node axis
+    with at most one nonzero term, so no per-element 2-D gather (slow on a
+    TPU) and no matmul pass rounds a weight."""
+    real = inst_mask > 0
+    # (I, Nb) one-hot of each instance's node; a padded instance has none,
+    # so its row and its column of W sum nothing
+    hot = (node_of[:, None] == jnp.arange(W_node.shape[0])[None, :]) & real[:, None]
+    rows = jnp.where(hot[:, :, None], W_node[None, :, :], 0.0).sum(axis=1)
+    W = jnp.where(hot.T[:, None, :], rows.T[:, :, None], 0.0).sum(axis=0)
+    remote = (cont_of[:, None] != cont_of[None, :]) & real[:, None] & real[None, :]
+    return W, remote
+
+
 def _simulate_core(
     arrays: dict,
     offered_per_tick: jnp.ndarray,  # (n_ticks,) total source ktuples per tick
@@ -666,8 +740,9 @@ def _simulate_core(
     n_cont = cont_cpus.shape[0]
     n_src = jnp.maximum(is_source.sum(), 1)
     if backend == "dense":
-        W = arrays["W"]
-        remote = arrays["remote"]
+        W, remote = _expand_routing(
+            arrays["W_node"], arrays["node_of"], cont_of, inst_mask
+        )
         rowsum = W.sum(axis=1)
     else:
         rowsum = arrays["rowsum"]
@@ -876,8 +951,10 @@ def _get_batch_kernel(batch: int, n_inst: int, n_cont: int, n_ticks: int,
                       backend: str = "dense", n_edges: int = 0,
                       d_out: int = 0, d_in: int = 0,
                       donate_batch: bool = True,
-                      samples_mode: str = "full"):
-    """``batch`` is the per-device batch when ``n_devices > 1``."""
+                      samples_mode: str = "full",
+                      n_nodes: int = 0):
+    """``batch`` is the per-device batch when ``n_devices > 1``;
+    ``n_nodes`` is the dense layout's node bucket (0 for sparse)."""
     # Donate the padded batch buffers (stacked structure arrays,
     # per-tick loads, seeds): they are rebuilt from host numpy on every
     # call, so XLA may reuse their memory for full-mode trajectories.
@@ -893,7 +970,8 @@ def _get_batch_kernel(batch: int, n_inst: int, n_cont: int, n_ticks: int,
     if platform == "cpu" or samples_mode == "summary":
         donate = ()
     key = (batch, n_inst, n_cont, n_ticks, sample_every, n_devices,
-           backend, n_edges, d_out, d_in, samples_mode, donate, platform)
+           backend, n_edges, d_out, d_in, samples_mode, donate, platform,
+           n_nodes)
     fn = _KERNEL_CACHE.get(key)
     if fn is None:
         _CACHE_STATS["misses"] += 1
@@ -920,8 +998,9 @@ def kernel_cache_info() -> dict:
     """Tick-kernel compile-cache statistics.  ``misses`` counts distinct
     ``(batch, bucket_shape, n_ticks, backend, platform)`` traces — i.e. XLA
     compilations.  ``entries`` describes each resident compiled kernel
-    (per-device batch, bucket shape, edge bucket, tick count, device count,
-    backend, platform), so BENCH extras record exactly what compiled.
+    (per-device batch, bucket shape, edge or node bucket, tick count,
+    device count, backend, platform), so BENCH extras record exactly what
+    compiled.
     """
     return {
         "size": len(_KERNEL_CACHE),
@@ -940,6 +1019,7 @@ def kernel_cache_info() -> dict:
                 "d_in": k[9],
                 "samples": k[10],
                 "platform": k[12],
+                "n_nodes": k[13],
             }
             for k in _KERNEL_CACHE
         ],
@@ -1322,6 +1402,7 @@ def simulate_batch(
     dedup: bool = True,
     cache=None,
     cache_token=None,
+    min_node_bucket: int = 0,
 ) -> list[SimResult]:
     """Evaluate N configurations in one vmapped (and device-sharded) call.
 
@@ -1374,7 +1455,10 @@ def simulate_batch(
     :data:`DEGREE_LADDER` buckets with the sticky ``min_degree_bucket``
     floor; padded edges
     carry zero share and padded ELL slots gather an exact 0.0, so results
-    are bitwise invariant to both buckets.
+    are bitwise invariant to both buckets.  The dense node-pair table is
+    padded to :data:`NODE_LADDER` with the sticky ``min_node_bucket``
+    floor; its padded entries are never looked up, so results are bitwise
+    invariant to it as well.
 
     ``resident=True`` caches the stacked, *device-resident* structure
     arrays keyed by (configs, params, buckets, backend, shard layout): a
@@ -1446,6 +1530,7 @@ def simulate_batch(
             min_degree_bucket=min_degree_bucket,
             resident=resident,
             samples_mode=samples,
+            min_node_bucket=min_node_bucket,
         )
 
     if not dedup and cache is None:
@@ -1580,6 +1665,7 @@ def _run_batch(
     min_degree_bucket: int,
     resident: bool,
     samples_mode: str = "full",
+    min_node_bucket: int = 0,
 ) -> list[SimResult]:
     """Execute one already-canonicalized batch (loads expanded per row,
     seeds resolved, tick count fixed): pad, stack, stage, and run the
@@ -1600,8 +1686,12 @@ def _run_batch(
         max(st.n_edges for st in structures),
         tick_kernel,
     )
-    n_edge_b = d_out_b = d_in_b = None
-    if backend == "sparse":
+    n_edge_b = d_out_b = d_in_b = n_node_b = None
+    if backend == "dense":
+        n_node_b = node_bucket_size(
+            max(st.n_nodes for st in structures), min_node_bucket
+        )
+    else:
         n_edge_b = edge_bucket_size(
             max(st.n_edges for st in structures), min_edge_bucket
         )
@@ -1632,7 +1722,7 @@ def _run_batch(
     if resident:
         stage_key = (
             tuple(configs), params, n_inst_b, n_cont_b, n_edge_b, d_out_b,
-            d_in_b, backend, n_dev, fill, _platform(),
+            d_in_b, n_node_b, backend, n_dev, fill, _platform(),
         )
         hit = _RESIDENT_CACHE.get(stage_key)
         if hit is not None:
@@ -1644,7 +1734,8 @@ def _run_batch(
     if stacked_dev is None:
         with span(spans.STAGE_PAD):
             padded = [
-                _padded_for(st, params, n_inst_b, n_cont_b, n_edge_b, d_out_b, d_in_b)
+                _padded_for(st, params, n_inst_b, n_cont_b, n_edge_b, d_out_b,
+                            d_in_b, n_node_b)
                 for st in structures
             ]
         with span(spans.STAGE_STACK):
@@ -1681,6 +1772,7 @@ def _run_batch(
         per_dev_B, n_inst_b, n_cont_b, n_ticks, params.sample_every, n_dev,
         backend, n_edge_b or 0, d_out_b or 0, d_in_b or 0,
         donate_batch=not resident, samples_mode=samples_mode,
+        n_nodes=n_node_b or 0,
     )
     with span(spans.STAGE_H2D):
         per_tick_dev = jnp.asarray(per_tick_in)

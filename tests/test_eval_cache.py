@@ -122,6 +122,27 @@ def test_vectorized_structure_bitwise_matches_loop_reference(workload):
     assert np.array_equal(st.edge_w, ref["W"][src, dst])
 
 
+@pytest.mark.parametrize("par, n_cont", [(2, 3), (3, 2), (1, 1)])
+@pytest.mark.parametrize("workload", WORKLOADS, ids=lambda w: w.__name__)
+def test_closed_form_routing_counts_match_edge_list(workload, par, n_cont):
+    """``n_edges`` / ``d_out`` / ``d_in`` and the SM fan-out costs come from
+    the node-pair table in closed form; they equal what the instance-level
+    edge list gives (the historical derivation)."""
+    st = build_structure(_cfg(workload(), par, n_cont), PARAMS)
+    src, dst = st.edge_src, st.edge_dst
+    assert st.n_edges == src.size
+    assert st.d_out == np.bincount(src, minlength=st.n_inst).max()
+    assert st.d_in == np.bincount(dst, minlength=st.n_inst).max()
+    conn = np.zeros((st.n_cont, st.n_cont), bool)
+    cross = st.cont_of[src] != st.cont_of[dst]
+    conn[st.cont_of[src[cross]], st.cont_of[dst[cross]]] = True
+    n_peers = (conn | conn.T).sum(axis=1)
+    want = PARAMS.sm_cost_per_ktuple * (1.0 + PARAMS.sm_fanout_coef * n_peers)
+    assert st.sm_cost_eff.dtype == want.dtype
+    assert np.array_equal(st.sm_cost_eff, want)
+    assert np.array_equal(st.W, st.W_node[np.ix_(st.node_of, st.node_of)])
+
+
 def test_vectorized_metrics_store_matches_reference():
     res = simulate_batch([_wc_cfg()], [300.0], duration_s=4.0, params=PARAMS)[0]
     store = res.to_metrics_store()

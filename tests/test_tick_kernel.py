@@ -36,6 +36,7 @@ from repro.core.dag import DagSpec, EdgeSpec, Grouping, NodeSpec
 from repro.kernels.stream_flow import stream_flow, stream_flow_reference
 from repro.streams import (
     EDGE_LADDER,
+    NODE_LADDER,
     SimParams,
     SimulatorEvaluator,
     adanalytics,
@@ -46,6 +47,7 @@ from repro.streams import (
     edge_bucket_size,
     kernel_cache_info,
     mobile_analytics,
+    node_bucket_size,
     resident_cache_info,
     resolve_tick_kernel,
     simulate,
@@ -256,6 +258,92 @@ def test_sticky_sparse_evaluator_compiles_at_most_twice():
     info = kernel_cache_info()
     assert info["misses"] <= 2
     assert all(e["backend"] == "sparse" for e in info["entries"])
+
+
+# ------------------------------------- dense layout: the node-pair table
+
+def test_node_bucket_size_ladder_and_floor():
+    assert node_bucket_size(1) == NODE_LADDER[0]
+    assert node_bucket_size(NODE_LADDER[0]) == NODE_LADDER[0]
+    assert node_bucket_size(NODE_LADDER[0] + 1) == NODE_LADDER[1]
+    assert node_bucket_size(NODE_LADDER[-1] + 1) == 2 * NODE_LADDER[-1]
+    assert node_bucket_size(3, floor=32) == 32
+
+
+@pytest.mark.parametrize("node_bucket", [None, 32])
+@pytest.mark.parametrize("inst_bucket", [32, 128])
+@pytest.mark.parametrize("workload", [wordcount, diamond, deep_pipeline])
+def test_dense_layout_expands_to_host_padded_routing(workload, inst_bucket, node_bucket):
+    """The kernel's entry expands ``node_of`` and the node-pair table into
+    exactly the (I, I) routing and remote matrices the host used to pad
+    and ship — bitwise, padded instances included (deep_pipeline's 8
+    nodes sit exactly on a node-bucket rung)."""
+    import jax
+    from repro.streams import simulator as sim
+
+    dag = workload()
+    cfg = round_robin_configuration(
+        dag, {n: 1 + i % 3 for i, n in enumerate(dag.node_names)}, 3, DIM
+    )
+    st = structure_for(cfg, PARAMS)
+    dense = sim.pad_structure(st, inst_bucket, 8, n_node_bucket=node_bucket)
+    assert "W" not in dense and "remote" not in dense
+    assert dense["W_node"].shape == (node_bucket or NODE_LADDER[0],) * 2
+    assert inst_bucket > st.n_inst
+    W, remote = jax.jit(sim._expand_routing)(
+        dense["W_node"], dense["node_of"], dense["cont_of"], dense["inst_mask"]
+    )
+    want_W = np.zeros((inst_bucket, inst_bucket), np.float32)
+    want_W[: st.n_inst, : st.n_inst] = st.W
+    want_remote = np.zeros((inst_bucket, inst_bucket), bool)
+    want_remote[: st.n_inst, : st.n_inst] = st.remote
+    W, remote = np.asarray(W), np.asarray(remote)
+    assert W.dtype == want_W.dtype and np.array_equal(W, want_W)
+    assert remote.dtype == want_remote.dtype and np.array_equal(remote, want_remote)
+
+
+def test_dense_results_are_bitwise_invariant_to_the_node_bucket():
+    dag = diamond()
+    cfg = round_robin_configuration(dag, {n: 2 for n in dag.node_names}, 3, DIM)
+    kw = dict(duration_s=2.0, params=PARAMS, tick_kernel="dense")
+    r1 = simulate_batch([cfg], [1e6], **kw)[0]
+    r2 = simulate_batch([cfg], [1e6], min_node_bucket=32, **kw)[0]
+    for k in r1.samples:
+        assert np.array_equal(
+            np.asarray(r1.samples[k]), np.asarray(r2.samples[k])
+        ), k
+
+
+def test_dense_batches_build_no_host_routing():
+    """A dense batch stages vectors and the node-pair table only: no host
+    (I, I) routing is built.  The sparse layout needs the edge list, so
+    it does build it, once per structure."""
+    from repro.streams import cache_stats, clear_structure_cache
+
+    dag = diamond()
+    cfgs = [round_robin_configuration(dag, {n: p for n in dag.node_names}, 3, DIM)
+            for p in (1, 2)]
+    clear_structure_cache()
+    simulate_batch(cfgs, [1e6, 300.0], duration_s=1.0, params=PARAMS,
+                   tick_kernel="dense", samples="summary")
+    assert cache_stats()["structure"]["dense_expansions"] == 0
+    simulate_batch(cfgs, [1e6, 300.0], duration_s=1.0, params=PARAMS,
+                   tick_kernel="sparse", samples="summary")
+    assert cache_stats()["structure"]["dense_expansions"] == len(cfgs)
+
+
+def test_sticky_node_bucket_keeps_one_dense_compile():
+    """The evaluator pins the node bucket like its other floors: a 9-node
+    DAG lifts it to 32, and a 2-node DAG scored after it reuses the same
+    compiled kernel."""
+    clear_kernel_cache()
+    ev = SimulatorEvaluator(params=PARAMS, duration_s=1.0, tick_kernel="dense")
+    chain = _random_dag(9, 0, np.random.default_rng(0))
+    ev.evaluate(round_robin_configuration(chain, {n: 1 for n in chain.node_names}, 3, DIM))
+    ev.evaluate(round_robin_configuration(wordcount(), {"W": 2, "C": 2}, 3, DIM))
+    info = kernel_cache_info()
+    assert info["misses"] == 1
+    assert [e["n_nodes"] for e in info["entries"]] == [32]
 
 
 # ------------------------------------------------------- Pallas fused step

@@ -4,7 +4,8 @@ Nothing here runs on a chip.  The TPU compiler compiles the batched tick
 kernel (``_get_batch_kernel``) for one chip of a described ``v5e:2x2``
 topology at the phase-1 shapes of ``chip_smoke.py``: 512 candidates in the
 512-instance / 128-container bucket, 800 ticks (the evaluator's 8 s
-horizon), dense and sparse (32,768-edge bucket), full and summary payloads.
+horizon), dense (8-node routing table, expanded to (I, I) on the device)
+and sparse (32,768-edge bucket), full and summary payloads.
 Each case must fit one chip's 16 GiB, and every one-hot contraction must
 ask for HIGHEST precision, so that no TPU pass rounds its f32 operands to
 bf16.  The topology is described inside a fixture (never at import), and
@@ -25,7 +26,7 @@ from repro.streams import SimParams, wordcount
 from repro.streams import simulator as sim
 
 HBM_BYTES = 16 * 2**30
-BATCH, N_INST, N_CONT, N_EDGES, DEGREE = 512, 512, 128, 32768, 64
+BATCH, N_INST, N_CONT, N_EDGES, DEGREE, N_NODES = 512, 512, 128, 32768, 64, 8
 N_TICKS = 800
 
 
@@ -70,7 +71,7 @@ def _batch_args(backend: str, sharding) -> tuple:
     cfg = round_robin_configuration(wordcount(), {"W": 2, "C": 2}, 2, ContainerDim())
     padded = sim.pad_structure(
         sim.structure_for(cfg, SimParams()), N_INST, N_CONT,
-        N_EDGES if backend == "sparse" else None, DEGREE, DEGREE,
+        N_EDGES if backend == "sparse" else None, DEGREE, DEGREE, N_NODES,
     )
 
     def spec(shape, dtype):
@@ -87,7 +88,7 @@ def test_tick_kernel_compiles_for_v5e(backend, samples, one_chip, isolated_compi
     kernel = sim._get_batch_kernel(
         BATCH, N_INST, N_CONT, N_TICKS, p.sample_every, 1, backend,
         N_EDGES if backend == "sparse" else 0, DEGREE, DEGREE,
-        samples_mode=samples,
+        samples_mode=samples, n_nodes=N_NODES if backend == "dense" else 0,
     )
     lowered = kernel.lower(
         *_batch_args(backend, one_chip), p.dt, p.noise_std,
